@@ -62,6 +62,7 @@ from repro.core.aggregate import _axes_is_leaf
 from repro.core.async_sim import (AsyncResult, StepTimeModel,
                                   StragglerSchedule, make_schedule)
 from repro.core.weights import masked_compute_theta
+from repro.parallel.sharding import auto_mesh, replicated, worker_split
 
 ASYNC_BACKENDS = ("async_einsum", "async_shard_map", "async_rs_ag")
 
@@ -211,6 +212,23 @@ def _resolve_policy(policy, strategy: str, a_tilde: float):
     return weights_mod.as_policy(policy, default_a=a_tilde)
 
 
+def _on_auto_mesh(ctx):
+    if ctx.mesh is None:
+        return ctx
+    return dataclasses.replace(ctx, mesh=auto_mesh(ctx.mesh))
+
+
+def _mesh_shardings(mesh):
+    """(worker-split, replicated) shardings for a round on ``mesh``, or
+    None without one. Every params and batch leaf of these rounds is
+    worker-stacked, so one split sharding covers each tree; pinning it on
+    both sides of the donated jit keeps input and output buffers the same
+    size on every device."""
+    if mesh is None:
+        return None
+    return worker_split(mesh), replicated(mesh)
+
+
 def build_async_round(grad_fn: Callable, axes: Dict, *, lr: float,
                       beta: float = 0.9, a_tilde: float = 1.0,
                       strategy: str = "boltzmann",
@@ -237,7 +255,7 @@ def build_async_round(grad_fn: Callable, axes: Dict, *, lr: float,
     ``grad_fn(params_stacked, batch) -> (losses (w,), grads_stacked)`` —
     the same contract as ``async_sim.run_parallel_sgd``.
     """
-    ctx = backends.DEFAULT_CONTEXT if ctx is None else ctx
+    ctx = _on_auto_mesh(backends.DEFAULT_CONTEXT if ctx is None else ctx)
     backend_obj = _resolve_backend(backend, ctx)
     pol = _resolve_policy(policy, strategy, a_tilde)
     w_axes = jax.tree.map(lambda ax: ("worker",) + tuple(ax), axes,
@@ -260,7 +278,16 @@ def build_async_round(grad_fn: Callable, axes: Dict, *, lr: float,
             return _advance(params, batch, active, ())[:3]
 
     if jit:
-        round_fn = jax.jit(round_fn, donate_argnums=(0,))
+        sh = _mesh_shardings(ctx.mesh)
+        if sh is None:
+            round_fn = jax.jit(round_fn, donate_argnums=(0,))
+        else:
+            split, rep = sh
+            n_in = 4 if pol.stateful else 3
+            round_fn = jax.jit(
+                round_fn, donate_argnums=(0,),
+                in_shardings=(split, split) + (rep,) * (n_in - 2),
+                out_shardings=(split,) + (rep,) * (n_in - 1))
     round_fn.stateful = pol.stateful
     return round_fn
 
@@ -287,7 +314,7 @@ def build_split_async_round(grad_fn: Callable, axes: Dict, *, lr: float,
             -> (params, theta, pstate)``
             masked policy theta + Eq. 10 aggregate + straggler late-join.
     """
-    ctx = backends.DEFAULT_CONTEXT if ctx is None else ctx
+    ctx = _on_auto_mesh(backends.DEFAULT_CONTEXT if ctx is None else ctx)
     backend_obj = _resolve_backend(backend, ctx)
     pol = weights_mod.as_policy(policy)
     w_axes = jax.tree.map(lambda ax: ("worker",) + tuple(ax), axes,
@@ -306,8 +333,20 @@ def build_split_async_round(grad_fn: Callable, axes: Dict, *, lr: float,
         return params, theta, pstate
 
     if jit:
-        local_fn = jax.jit(local_fn, donate_argnums=(0,))
-        agg_fn = jax.jit(agg_fn, donate_argnums=(0,))
+        sh = _mesh_shardings(ctx.mesh)
+        if sh is None:
+            local_fn = jax.jit(local_fn, donate_argnums=(0,))
+            agg_fn = jax.jit(agg_fn, donate_argnums=(0,))
+        else:
+            # losses stay split: measure_round_times reads each device's
+            # own rows as they arrive.
+            split, rep = sh
+            local_fn = jax.jit(local_fn, donate_argnums=(0,),
+                               in_shardings=(split, split),
+                               out_shardings=(split, split))
+            agg_fn = jax.jit(agg_fn, donate_argnums=(0,),
+                             in_shardings=(split, split, rep, rep),
+                             out_shardings=(split, rep, rep))
     return local_fn, agg_fn
 
 

@@ -32,7 +32,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.aggregate import _axes_is_leaf, fma_late_join, is_worker_leaf
 
@@ -70,7 +69,7 @@ def all_reduce_m_phase(payload: jax.Array, theta: jax.Array, mesh: Mesh,
     spec = P(waxes, *([None] * (ndim - 1)))
     out_spec = P(*([None] * (ndim - 1)))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(spec, P(waxes)),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec, P(waxes)),
                        out_specs=out_spec)
     def run(p_local, t_local):
         contrib = t_local.astype(reduce_dtype).reshape(
@@ -94,7 +93,7 @@ def reduce_scatter_phase(payload: jax.Array, theta: jax.Array, mesh: Mesh,
     waxes = _worker_axes_in(mesh)
     ax = _collective_axis(waxes)
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(waxes, None), P(waxes)),
                        out_specs=P(waxes))
     def run(p_local, t_local):
@@ -117,11 +116,11 @@ def all_gather_phase(m_scat: jax.Array, mesh: Mesh) -> jax.Array:
     waxes = _worker_axes_in(mesh)
     ax = _collective_axis(waxes)
 
-    # check_rep=False: a tiled all_gather over the full worker axes IS
-    # replicated along them, but shard_map's rep checker only infers
-    # replication through psum.
-    @functools.partial(shard_map, mesh=mesh, in_specs=P(waxes),
-                       out_specs=P(None), check_rep=False)
+    # check_vma=False: a tiled all_gather over the full worker axes IS
+    # replicated along them, but shard_map's varying-axes check does not
+    # infer that.
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(waxes),
+                       out_specs=P(None), check_vma=False)
     def run(m_local):
         return jax.lax.all_gather(m_local, ax,
                                   tiled=True).astype(jnp.float32)

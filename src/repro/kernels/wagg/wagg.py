@@ -55,31 +55,47 @@ def auto_block_n(p: int, block_n: int, bytes_per_col: int,
                  budget: int = VMEM_BUDGET_BYTES) -> int:
     """Shrink ``block_n`` until the (p, block_n) tile working set fits VMEM.
 
-    ``bytes_per_col`` is the per-element footprint across everything resident
-    per tile (x in f32 + out + the separate payload when there is one). The
-    block halves until ``p * block_n * bytes_per_col <= budget`` or the
-    128-column floor, instead of over-allocating for large p.
+    ``bytes_per_col`` is the footprint per worker row and column of
+    everything resident per tile: the double-buffered x, out and payload
+    streams plus the kernel's f32 temporaries (``tile_bytes_per_col``).
+    VMEM lays rows out in 8-row sublane tiles, so p is rounded up to a
+    multiple of 8. The block halves until the set fits ``budget`` or the
+    128-column floor, instead of being refused by the compiler for large p.
     """
+    rows = -(-p // 8) * 8
     bn = block_n
-    while bn > _MIN_BLOCK_N and p * bn * bytes_per_col > budget:
+    while bn > _MIN_BLOCK_N and rows * bn * bytes_per_col > budget:
         bn //= 2
+    if bn != block_n:               # a partial block must be lane-aligned
+        bn = max(_MIN_BLOCK_N, bn - bn % 128)
     return bn
+
+
+def tile_bytes_per_col(payload_itemsize: int = 0) -> int:
+    """Per-row, per-column VMEM bytes of one grid step: x and out in f32,
+    double-buffered, plus a separate payload stream (double-buffered) and
+    its f32 widening in the kernel body."""
+    per = 2 * (4 + 4)
+    if payload_itemsize:
+        per += 2 * payload_itemsize + 4
+    return per
 
 
 def _wagg_kernel(*refs, beta: float, masked: bool, separate_payload: bool):
     it = iter(refs)
-    theta = next(it)[...].astype(jnp.float32)     # (p,)  scale pre-folded
-    active = next(it)[...] if masked else None    # (p,)  f32 0/1
+    theta = next(it)[...]                         # (p, 1) f32, scale folded
+    active = next(it)[...] if masked else None    # (p, 1) f32 0/1
     q_ref = next(it) if separate_payload else None
     x_ref = next(it)
     o_ref = next(it)
     x = x_ref[...].astype(jnp.float32)            # (p, bn)
     src = q_ref[...].astype(jnp.float32) if separate_payload else x
-    m = jnp.einsum("p,pn->n", theta, src)         # VPU reduction over workers
-    out = (1.0 - beta) * x + beta * m[None, :]
+    # VPU reduction over workers: lane-broadcast multiply, sublane sum.
+    m = jnp.sum(theta * src, axis=0, keepdims=True)          # (1, bn)
+    out = (1.0 - beta) * x + beta * m
     if masked:
         # Alg. 4 late-join: straggler rows adopt the aggregate wholesale.
-        out = jnp.where(active[:, None] != 0, out, m[None, :])
+        out = jnp.where(active != 0, out, m)
     o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -104,18 +120,22 @@ def wagg_fused(x: jax.Array, theta: jax.Array, beta: float,
     p, n = x.shape
     separate = payload is not None
     masked = active is not None
-    per_col = 2 * 4 + (jnp.dtype(payload.dtype).itemsize if separate else 0)
+    per_col = tile_bytes_per_col(
+        jnp.dtype(payload.dtype).itemsize if separate else 0)
     bn = auto_block_n(p, min(block_n, n), per_col)
     pad = (-n) % bn
     xp = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
     np_ = n + pad
 
     tile = pl.BlockSpec((p, bn), lambda j: (0, j))
-    vec = pl.BlockSpec((p,), lambda j: (0,))
-    in_specs, operands = [vec], [theta]
+    # (p, 1) columns: a 2-D block equal to the array's shape is legal for
+    # Mosaic, where a 1-D (p,) block is not.
+    vec = pl.BlockSpec((p, 1), lambda j: (0, 0))
+    in_specs = [vec]
+    operands = [theta.astype(jnp.float32).reshape(p, 1)]
     if masked:
         in_specs.append(vec)
-        operands.append(active.astype(jnp.float32))
+        operands.append(active.astype(jnp.float32).reshape(p, 1))
     if separate:
         qp = jnp.pad(payload, ((0, 0), (0, pad))) if pad else payload
         in_specs.append(tile)

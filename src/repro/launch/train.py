@@ -3,10 +3,13 @@
     PYTHONPATH=src python -m repro.launch.train --arch stablelm-1.6b --smoke \
         --workers 4 --rounds 20
 
-On a real TPU pod this builds the production mesh and shards the worker-
-stacked state per parallel/sharding.py; on CPU (this container) it runs the
-reduced config on the host device with the same code path — the mesh only
-changes the `in_shardings`, never the program.
+It runs every worker on the default device: it builds no mesh, so the
+explicit-collective schedules (``shard_map``/``rs_ag``) are not available
+here. A mesh-placed run, one worker per chip, goes through
+``Trainer(mesh=...)`` (see ``chip_smoke.py --four-chips``). ``--smoke``
+selects the reduced config that runs on a CPU. The persistent compilation
+cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, else to ``.jax_cache/``
+at the root of the checkout.
 """
 from __future__ import annotations
 
@@ -20,6 +23,7 @@ from repro.checkpoint import save
 from repro.configs import (TrainConfig, WASGDConfig, get_config,
                            get_smoke_config)
 from repro.data import OrderedDataset, RoundPrefetcher, make_tokens
+from repro.launch.cache import enable_compile_cache
 from repro.models import init_params
 from repro.train import Trainer
 from repro.train.lm import make_lm_loss
@@ -87,6 +91,7 @@ def main():
                          "'speculative' also runs the next Judge forward on "
                          "pre-aggregate params (wasgd/wasgd+ rules only)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     print(f"arch={cfg.name} family={cfg.family} "

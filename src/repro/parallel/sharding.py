@@ -16,7 +16,7 @@ import math
 from typing import Dict, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 LogicalAxes = Tuple[Optional[str], ...]
 Rule = Union[None, str, Tuple[str, ...]]
@@ -145,6 +145,55 @@ def tree_shardings(mesh: Mesh, shapes_tree, axes_tree, rules=None):
 def num_workers(mesh: Mesh) -> int:
     """WASGD worker count = product of the worker-axis mesh dims."""
     return _axis_size(mesh, _present(mesh, TRAIN_RULES["worker"]))
+
+
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with every axis ``Auto``.
+
+    ``jax.make_mesh`` makes ``Explicit`` axes, whose arrays carry their
+    sharding in their type and must agree on it in every op. The training
+    round is written for compiler-chosen (``Auto``) shardings, so its
+    placement and its ``shard_map`` collectives run on this view; one round
+    must not mix the two, since JAX refuses ops over arrays of both.
+    Idempotent."""
+    auto = (AxisType.Auto,) * len(mesh.axis_names)
+    if tuple(mesh.axis_types) == auto:
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names, axis_types=auto)
+
+
+def worker_split(mesh: Mesh) -> NamedSharding:
+    """Leading dim split over the mesh's worker axes, the rest replicated."""
+    return NamedSharding(auto_mesh(mesh),
+                         P(_present(mesh, TRAIN_RULES["worker"])))
+
+
+def replicated(mesh: Mesh) -> NamedSharding:
+    return NamedSharding(auto_mesh(mesh), P())
+
+
+def stacked_shardings(mesh: Mesh, tree, n_workers: int):
+    """Sharding tree for a worker-stacked pytree of arrays or shapes.
+
+    Every leaf whose leading dim is a multiple of ``n_workers`` (params,
+    optimizer moments, energies and per-worker policy state, and the
+    worker-major round batches) is split over the worker axes; the rest
+    (the round counter, scalars) is replicated. Each worker's rows then sit
+    on the device that runs its worker, and a jit that pins these shardings
+    on both sides can donate its state buffer for buffer.
+    """
+    shards = num_workers(mesh)
+    if n_workers % shards:
+        raise ValueError(
+            f"{n_workers} workers do not divide over the mesh's {shards} "
+            f"worker shards")
+    split, rep = worker_split(mesh), replicated(mesh)
+
+    def pick(x):
+        shape = getattr(x, "shape", ())
+        return split if shape and shape[0] % n_workers == 0 else rep
+
+    return jax.tree.map(pick, tree)
 
 
 def bytes_of(shape: Sequence[int], dtype) -> int:

@@ -27,6 +27,8 @@ from repro.data.pipeline import RoundPrefetcher
 from repro.obs import (NULL, MembershipChange, RoundTrace, WorkerAssessment,
                        summarize_policy_state)
 from repro.optim import make_optimizer
+from repro.parallel.sharding import (auto_mesh, replicated,
+                                     stacked_shardings, worker_split)
 from repro.train.state import TrainState, init_state
 from repro.train.step import build_train_step, init_comm_state, wasgd_rule
 from repro.train import step as step_mod
@@ -64,9 +66,15 @@ class Trainer:
         """``mesh`` feeds the aggregation-backend context — required when
         ``tcfg.wasgd`` selects a schedule that places explicit collectives
         (``shard_map``/``rs_ag``, incl. legacy ``sharded_aggregate=True``).
-        ``overlap`` (nullary compute thunk; may return any pytree) rides
-        between the schedule's collective phases; its per-round result lands
-        in ``history[r]["overlap"]``.
+        With a mesh the state lives on it: every worker-stacked leaf has its
+        worker rows split over the mesh's worker axes
+        (``parallel.sharding.stacked_shardings``), each round's batch is
+        staged the same way, and the jitted round pins those shardings on
+        its inputs and outputs so the donated state aliases buffer for
+        buffer. ``resize`` and ``resume`` cannot keep that placement yet and
+        raise under a mesh. ``overlap`` (nullary compute thunk; may return
+        any pytree) rides between the schedule's collective phases; its
+        per-round result lands in ``history[r]["overlap"]``.
 
         ``pipeline="parity" | "speculative"`` software-pipelines the round
         (``train/step.py``): ``run`` wraps the batch iterator in a
@@ -91,21 +99,33 @@ class Trainer:
             raise ValueError(
                 f"pipeline={pipeline!r} threads the seam thunk through the "
                 f"wasgd/wasgd+ rules only (got rule={rule!r})")
-        if replicate:
-            params, axes = replicate_workers(
-                params, axes, n_workers,
-                expert_copies=getattr(tcfg, "expert_copies", False))
-        self.axes = axes
         self.optimizer = make_optimizer(
             tcfg.optimizer, tcfg.learning_rate, tcfg.momentum,
             tcfg.weight_decay)
-        opt_state = self.optimizer.init(params)
-        comm_state = init_comm_state(rule, params, axes, n_workers,
-                                     wcfg=tcfg.wasgd)
-        self.state: TrainState = init_state(params, opt_state, n_workers,
-                                            comm_state)
+
+        def build(params):
+            """The initial TrainState; records the worker-stacked axes."""
+            w_axes = axes
+            if replicate:
+                params, w_axes = replicate_workers(
+                    params, axes, n_workers,
+                    expert_copies=getattr(tcfg, "expert_copies", False))
+            self.axes = w_axes
+            opt_state = self.optimizer.init(params)
+            comm_state = init_comm_state(rule, params, w_axes, n_workers,
+                                         wcfg=tcfg.wasgd)
+            return init_state(params, opt_state, n_workers, comm_state)
+
         self._loss_fn = loss_fn
-        self._mesh = mesh
+        self._mesh = None if mesh is None else auto_mesh(mesh)
+        if mesh is None:
+            self.state: TrainState = build(params)
+        else:
+            # Built by one jit straight into its shardings: the p worker
+            # copies never exist together on one device.
+            self._state_sh = stacked_shardings(
+                self._mesh, jax.eval_shape(build, params), n_workers)
+            self.state = jax.jit(build, out_shardings=self._state_sh)(params)
         self._overlap = overlap
         self._jit = jit
         self._easgd_alpha = easgd_alpha
@@ -142,12 +162,44 @@ class Trainer:
                                 self.tcfg.wasgd, self.n_workers,
                                 rule=rule_fn, pipeline=self.pipeline)
         self._primer = getattr(step, "primer", None)
-        if self._jit:
+        if self._jit and self._mesh is not None:
+            step = self._jit_on_mesh(step)
+        elif self._jit:
             step = jax.jit(step, donate_argnums=(0,))
             if self._primer is not None:
                 self._primer = jax.jit(self._primer)
         self._step = step
         self._step_cache[self.n_workers] = (self._step, self._primer)
+
+    def _jit_on_mesh(self, step):
+        """Jit the round with the state's shardings pinned on both sides:
+        batches, the staged next microbatch and the pipeline carry are
+        worker-major and split like the state; metrics come back
+        replicated."""
+        mesh, state_sh = self._mesh, self._state_sh
+        split, rep = worker_split(mesh), replicated(mesh)
+        if self._primer is None:
+            return jax.jit(step, donate_argnums=(0,),
+                           in_shardings=(state_sh, split),
+                           out_shardings=(state_sh, rep))
+        self._primer = jax.jit(self._primer,
+                               in_shardings=(state_sh.params, split),
+                               out_shardings=split)
+        return jax.jit(step, donate_argnums=(0,),
+                       in_shardings=(state_sh, split, split, split),
+                       out_shardings=(state_sh, rep, split))
+
+    def _stage(self, batch):
+        """Put one round's host batch on the devices that consume it."""
+        if self._mesh is None:
+            return jax.device_put(batch)
+        return jax.device_put(batch, worker_split(self._mesh))
+
+    def _no_mesh(self, what: str):
+        if self._mesh is not None:
+            raise NotImplementedError(
+                f"{what} under a mesh would gather the worker-split state "
+                f"onto one device; it is not supported yet")
 
     def _policy_for_resize(self):
         if self.rule_name not in ("wasgd", "wasgd+"):
@@ -169,6 +221,7 @@ class Trainer:
         new_p = int(new_p)
         if new_p == self.n_workers:
             return None
+        self._no_mesh("resize")
         comm = init_comm_state(self.rule_name, self.state.params, self.axes,
                                new_p, wcfg=self.tcfg.wasgd,
                                prev=self.state.comm_state)
@@ -220,6 +273,7 @@ class Trainer:
         saved survivors land bitwise in their slots, extra slots are filled
         by the resize machinery's late-join rule."""
         from repro.checkpoint import restore, saved_topology
+        self._no_mesh("resume")
         info = saved_topology(path)
         topo = info["topology"]
         saved_p = int(topo.get("p", self.n_workers))
@@ -489,10 +543,10 @@ class Trainer:
                     cs = ({**cs, "active": mask} if isinstance(cs, dict)
                           else mask)
                     self.state = self.state._replace(comm_state=cs)
-                if transfer_guard is not None:
-                    batch = jax.device_put(batch)
+                if transfer_guard is not None or self._mesh is not None:
+                    batch = self._stage(batch)
                     if self.pipeline is not None:
-                        next_first = jax.device_put(next_first)
+                        next_first = self._stage(next_first)
                 host_staging_s = (time.perf_counter() - t_host
                                   if obs_on else 0.0)
                 phased = self._phased_step() if obs_on else None
