@@ -168,6 +168,17 @@ def resize_worker_leaves(params: Dict, axes: Dict, new_p: int,
     return jax.tree.map(visit, params, axes, is_leaf=_axes_is_leaf)
 
 
+def _single_copy(ax, expert_copies: bool) -> bool:
+    return not expert_copies and isinstance(ax, tuple) and "experts" in ax
+
+
+def worker_axes(axes: Dict, expert_copies: bool = False) -> Dict:
+    """The axes tree ``replicate_workers`` returns, without the params."""
+    return jax.tree.map(
+        lambda ax: ax if _single_copy(ax, expert_copies)
+        else ("worker",) + tuple(ax), axes, is_leaf=_axes_is_leaf)
+
+
 def replicate_workers(params: Dict, axes: Dict, n_workers: int,
                       expert_copies: bool = False):
     """Single-copy params -> (w, ...) worker copies (+ updated axes tree).
@@ -176,15 +187,9 @@ def replicate_workers(params: Dict, axes: Dict, n_workers: int,
     ``expert_copies`` — the "worker" expert-sharding policy where experts
     join the weighted aggregation (§Perf, memory permitting)."""
     def rep(x, ax):
-        if not expert_copies and isinstance(ax, tuple) and "experts" in ax:
+        if _single_copy(ax, expert_copies):
             return x
         return jnp.broadcast_to(x[None], (n_workers,) + x.shape)
 
-    def rep_ax(ax):
-        if not expert_copies and isinstance(ax, tuple) and "experts" in ax:
-            return ax
-        return ("worker",) + tuple(ax)
-
     new_params = jax.tree.map(rep, params, axes, is_leaf=_axes_is_leaf)
-    new_axes = jax.tree.map(rep_ax, axes, is_leaf=_axes_is_leaf)
-    return new_params, new_axes
+    return new_params, worker_axes(axes, expert_copies)

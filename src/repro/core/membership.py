@@ -229,17 +229,15 @@ def _resize_params_like(tree: Any, axes: Dict, new_p: int) -> Any:
     return jax.tree.map(visit, tree, axes, is_leaf=_axes_is_leaf)
 
 
-def resize_opt_state(opt_state: Any, axes: Dict, new_p: int) -> Any:
-    """Re-shard optimizer state across a membership resize.
+def map_opt_state(opt_state: Any, axes: Dict, on_params, on_other) -> Any:
+    """Rebuild an optimizer state with ``on_params`` applied to each of its
+    params-structured subtrees and ``on_other`` to anything else.
 
     Optimizer state in this substrate is element-wise over the params
     (optim/optimizers.py), so it either IS params-structured (momentum
     buffers, ``_tree_zeros``), is empty (plain SGD), or is a container
     (NamedTuple/tuple) whose fields are each params-structured or scalar
-    (AdamW's ``(mu, nu, count)``). Worker leaves resize with survivor-mean
-    newcomer rows — a joiner inherits the fleet's aggregate momentum/
-    moments rather than restarting cold; scalars (step counts) are fleet
-    state and pass through.
+    (AdamW's ``(mu, nu, count)``).
     """
     target = _params_structure(axes)
 
@@ -247,11 +245,23 @@ def resize_opt_state(opt_state: Any, axes: Dict, new_p: int) -> Any:
         if isinstance(sub, tuple) and not sub:
             return sub
         if jax.tree_util.tree_structure(sub) == target:
-            return _resize_params_like(sub, axes, new_p)
+            return on_params(sub)
         if hasattr(sub, "_fields"):                    # NamedTuple
             return type(sub)(*(visit(getattr(sub, f)) for f in sub._fields))
         if isinstance(sub, (tuple, list)):
             return type(sub)(visit(v) for v in sub)
+        return on_other(sub)
+
+    return visit(opt_state)
+
+
+def resize_opt_state(opt_state: Any, axes: Dict, new_p: int) -> Any:
+    """Re-shard optimizer state across a membership resize: worker leaves
+    resize with survivor-mean newcomer rows — a joiner inherits the fleet's
+    aggregate momentum/moments rather than restarting cold; scalars (step
+    counts) are fleet state and pass through.
+    """
+    def scalar(sub):
         if hasattr(sub, "ndim") and jnp.asarray(sub).ndim == 0:
             return sub
         raise ValueError(
@@ -259,7 +269,9 @@ def resize_opt_state(opt_state: Any, axes: Dict, new_p: int) -> Any:
             f"{type(sub).__name__} across a membership resize; expected "
             f"(), a params-structured tree, or a container of those")
 
-    return visit(opt_state)
+    return map_opt_state(
+        opt_state, axes, lambda t: _resize_params_like(t, axes, new_p),
+        scalar)
 
 
 def resize_train_state(state, axes: Dict, new_p: int, policy=None,

@@ -10,8 +10,12 @@ map reads the block table — delivered ahead of the kernel body via
 ``PrefetchScalarGridSpec`` scalar prefetch — so each grid step streams one
 *physical* block, all kv heads of it, straight from the pool, no gather
 materialization. Within a step every query head scores the block's
-``bs * kv`` (position, head) rows on the MXU and keeps its own head's;
-decode is bound by the K/V bytes, not by these extra products.
+``bs * kv`` (position, head) rows on the MXU and keeps its own head's, so
+the masked score tile is ``(kv*g, bs*kv)``: kv times the useful work, and
+its cost grows as kv squared. On a TPU v5e at kv=32, hd=64, bs=16 a grid
+step takes about 1.4 us, of which roughly half scales with that tile; the
+call reaches about 0.11 of the K/V-bytes roofline (``PERF.md``, traced
+with ``benchmarks/paged_decode_trace.py``).
 
 Layouts (per layer, static):
 

@@ -172,29 +172,5 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(auto_mesh(mesh), P())
 
 
-def stacked_shardings(mesh: Mesh, tree, n_workers: int):
-    """Sharding tree for a worker-stacked pytree of arrays or shapes.
-
-    Every leaf whose leading dim is a multiple of ``n_workers`` (params,
-    optimizer moments, energies and per-worker policy state, and the
-    worker-major round batches) is split over the worker axes; the rest
-    (the round counter, scalars) is replicated. Each worker's rows then sit
-    on the device that runs its worker, and a jit that pins these shardings
-    on both sides can donate its state buffer for buffer.
-    """
-    shards = num_workers(mesh)
-    if n_workers % shards:
-        raise ValueError(
-            f"{n_workers} workers do not divide over the mesh's {shards} "
-            f"worker shards")
-    split, rep = worker_split(mesh), replicated(mesh)
-
-    def pick(x):
-        shape = getattr(x, "shape", ())
-        return split if shape and shape[0] % n_workers == 0 else rep
-
-    return jax.tree.map(pick, tree)
-
-
 def bytes_of(shape: Sequence[int], dtype) -> int:
     return math.prod(shape) * jax.numpy.dtype(dtype).itemsize

@@ -19,16 +19,17 @@ import numpy as np
 
 from repro.configs.base import TrainConfig, WASGDConfig
 from repro.core import replicate_workers
+from repro.core.aggregate import worker_axes
 from repro.core.membership import (MembershipSchedule, WorkerSet,
-                                   resize_train_state)
+                                   map_opt_state, resize_train_state)
 from repro.core.order import OrderState
 from repro.core.weights import policy_from_config
 from repro.data.pipeline import RoundPrefetcher
 from repro.obs import (NULL, MembershipChange, RoundTrace, WorkerAssessment,
                        summarize_policy_state)
 from repro.optim import make_optimizer
-from repro.parallel.sharding import (auto_mesh, replicated,
-                                     stacked_shardings, worker_split)
+from repro.parallel.sharding import (auto_mesh, num_workers, replicated,
+                                     tree_shardings, worker_split)
 from repro.train.state import TrainState, init_state
 from repro.train.step import build_train_step, init_comm_state, wasgd_rule
 from repro.train import step as step_mod
@@ -43,6 +44,30 @@ def _wasgd_rule_for(tcfg, mesh=None, overlap=None):
         return step_mod.async_wasgd_rule(tcfg.wasgd, mesh=mesh,
                                          overlap=overlap)
     return step_mod.wasgd_rule(tcfg.wasgd, mesh=mesh, overlap=overlap)
+
+
+def _state_shardings(mesh, shapes: TrainState, axes: Dict,
+                     n_workers: int) -> TrainState:
+    """Where a ``TrainState`` lives on ``mesh``: the params by their logical
+    axes (``parallel/sharding.py``; the worker axis goes to the mesh's
+    worker axes), optimizer moments like the params they follow, and the
+    rest (round counter, energies, comm state) replicated."""
+    shards = num_workers(mesh)
+    if n_workers % shards:
+        raise ValueError(
+            f"{n_workers} workers do not divide over the mesh's {shards} "
+            f"worker shards")
+    rep = replicated(mesh)
+    params = tree_shardings(mesh, shapes.params, axes)
+
+    def replicate(tree):
+        return jax.tree.map(lambda _: rep, tree)
+
+    return TrainState(
+        step=rep, params=params,
+        opt_state=map_opt_state(shapes.opt_state, axes, lambda _: params,
+                                replicate),
+        energy=rep, comm_state=replicate(shapes.comm_state))
 
 
 RULES = {
@@ -66,10 +91,11 @@ class Trainer:
         """``mesh`` feeds the aggregation-backend context — required when
         ``tcfg.wasgd`` selects a schedule that places explicit collectives
         (``shard_map``/``rs_ag``, incl. legacy ``sharded_aggregate=True``).
-        With a mesh the state lives on it: every worker-stacked leaf has its
-        worker rows split over the mesh's worker axes
-        (``parallel.sharding.stacked_shardings``), each round's batch is
-        staged the same way, and the jitted round pins those shardings on
+        With a mesh the state lives on it: every worker-stacked params leaf
+        (and its optimizer moments) has its worker rows split over the
+        mesh's worker axes by the logical-axis rules (``_state_shardings``),
+        each round's batch is staged split the same way, and the jitted
+        round pins those shardings on
         its inputs and outputs so the donated state aliases buffer for
         buffer. ``resize`` and ``resume`` cannot keep that placement yet and
         raise under a mesh. ``overlap`` (nullary compute thunk; may return
@@ -103,16 +129,16 @@ class Trainer:
             tcfg.optimizer, tcfg.learning_rate, tcfg.momentum,
             tcfg.weight_decay)
 
+        expert_copies = getattr(tcfg, "expert_copies", False)
+        self.axes = worker_axes(axes, expert_copies) if replicate else axes
+
         def build(params):
-            """The initial TrainState; records the worker-stacked axes."""
-            w_axes = axes
+            """The initial TrainState."""
             if replicate:
-                params, w_axes = replicate_workers(
-                    params, axes, n_workers,
-                    expert_copies=getattr(tcfg, "expert_copies", False))
-            self.axes = w_axes
+                params, _ = replicate_workers(params, axes, n_workers,
+                                              expert_copies=expert_copies)
             opt_state = self.optimizer.init(params)
-            comm_state = init_comm_state(rule, params, w_axes, n_workers,
+            comm_state = init_comm_state(rule, params, self.axes, n_workers,
                                          wcfg=tcfg.wasgd)
             return init_state(params, opt_state, n_workers, comm_state)
 
@@ -123,8 +149,9 @@ class Trainer:
         else:
             # Built by one jit straight into its shardings: the p worker
             # copies never exist together on one device.
-            self._state_sh = stacked_shardings(
-                self._mesh, jax.eval_shape(build, params), n_workers)
+            self._state_sh = _state_shardings(
+                self._mesh, jax.eval_shape(build, params), self.axes,
+                n_workers)
             self.state = jax.jit(build, out_shardings=self._state_sh)(params)
         self._overlap = overlap
         self._jit = jit

@@ -64,6 +64,38 @@ SCRIPT = textwrap.dedent("""
 """)
 
 
+def test_mesh_state_shardings_follow_the_logical_axes():
+    """Params split by their logical axes, AdamW moments like the params
+    they follow, everything else replicated — no guessing from shapes (the
+    replicated leaves here all have a leading dim of p)."""
+    import functools
+    import jax
+    from jax.sharding import PartitionSpec as P
+    from repro.configs import TrainConfig, WASGDConfig
+    from repro.launch.mesh import make_host_mesh
+    from repro.models import cnn
+    from repro.models.param import build
+    from repro.train import Trainer
+
+    p = 2
+    params, axes = build(functools.partial(
+        cnn.mlp_init, d_in=8, d_hidden=16, n_classes=3), jax.random.key(0))
+    tcfg = TrainConfig(learning_rate=0.05, optimizer="adamw",
+                       wasgd=WASGDConfig(tau=2, policy="ema(0.5)|boltzmann"))
+    tr = Trainer(lambda p_, b: (0.0, {}), params, axes, tcfg, p,
+                 mesh=make_host_mesh(data=1, model=1))
+    sh = tr._state_sh
+    specs = [s.spec for s in jax.tree.leaves(sh.params)]
+    assert specs and all(s[0] == "data" for s in specs)
+    mu, nu, count = sh.opt_state
+    assert jax.tree.leaves(mu) == jax.tree.leaves(sh.params)
+    assert jax.tree.leaves(nu) == jax.tree.leaves(sh.params)
+    assert count.spec == P()
+    rest = [sh.step, sh.energy] + jax.tree.leaves(sh.comm_state)
+    assert len(rest) > 2 and all(s.spec == P() for s in rest)
+    assert all(x.shape[0] == p for x in jax.tree.leaves(tr.state.comm_state))
+
+
 def test_trainer_on_4_device_mesh_matches_einsum():
     """rs_ag:f32 and shard_map:f32 through Trainer(mesh=) equal einsum:f32
     on the same mesh, and every params leaf holds one worker row on each of
