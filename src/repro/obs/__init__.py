@@ -9,6 +9,10 @@ summarize a recorded run with ``tools/obs_report.py``). Producers:
 MembershipChange), ``AsyncCheckpointer`` (CheckpointSave),
 ``ContinuousEngine(telemetry=)`` (ServeSample), ``HotSwapBridge``
 (HotSwap).
+
+``span`` (``obs/spans.py``) writes host spans into a profiler trace on the
+device's clock; ``ContinuousEngine.step`` opens its ``serve.*`` spans
+through it.
 """
 from repro.obs.events import (CheckpointSave, HotSwap, MembershipChange,
                               PHASE_NAMES, RoundTrace, ServeSample,
@@ -16,10 +20,11 @@ from repro.obs.events import (CheckpointSave, HotSwap, MembershipChange,
                               summarize_policy_state)
 from repro.obs.sinks import (JsonlSink, NULL, NullSink, RingSink, Telemetry,
                              read_events)
+from repro.obs.spans import span
 
 __all__ = [
     "CheckpointSave", "HotSwap", "JsonlSink", "MembershipChange", "NULL",
     "NullSink", "PHASE_NAMES", "RingSink", "RoundTrace", "ServeSample",
     "Telemetry", "WorkerAssessment", "event_from_record", "read_events",
-    "summarize_policy_state", "to_record",
+    "span", "summarize_policy_state", "to_record",
 ]

@@ -93,10 +93,11 @@ class ServeSample:
     """One ``ContinuousEngine.step()`` scheduling round.
 
     ``ttft_s`` holds time-to-first-token for the requests admitted this
-    step (submit -> first token sampled at the end of their prefill);
-    ``e2e_s`` holds submit-to-finish latency for requests that finished
-    this step. ``itl_s`` is the chunk's mean inter-token latency
-    (fenced chunk wall / decode-loop iterations)."""
+    step: submit -> their first tokens read back on the host, at the end
+    of this step's chunk (``Request.t_first - Request.t_submit``; the
+    admission is not fenced). ``e2e_s`` holds submit-to-finish latency
+    for requests that finished this step. ``itl_s`` is the chunk's mean
+    inter-token latency (fenced chunk wall / decode-loop iterations)."""
     kind = "serve_sample"
     chunk_s: float
     steps: int

@@ -42,7 +42,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import decode_step, decode_step_paged, init_cache, prefill
-from repro.obs import NULL, HotSwap, ServeSample
+from repro.obs import NULL, HotSwap, ServeSample, span
 from repro.serve.paged_cache import PagedCache
 from repro.serve.scheduler import Request, Scheduler
 
@@ -138,6 +138,14 @@ class ContinuousEngine:
     ``eos_id``, when set, is a stop token: a row that emits it finishes
     regardless of remaining budget. The check compiles into the loop's
     done-flags — the host never reads a token to test it.
+
+    ``step()`` opens ``serve.*`` host spans (``repro.obs.span``) that a
+    profiler trace records on the device's clock: ``serve.step`` holds
+    ``serve.admit`` (with a ``serve.prefill`` per prefill group and a
+    ``serve.scatter`` per request, args ``rid`` and ``slot``),
+    ``serve.tables``, ``serve.dispatch``, ``serve.readback`` and
+    ``serve.collect``. The device programs are named ``jit_prefill``,
+    ``jit_scatter``, ``jit_admit_state`` and ``jit_decode_chunk``.
     """
 
     def __init__(self, cfg: ModelConfig, params: Dict, n_slots: int = 8,
@@ -147,9 +155,12 @@ class ContinuousEngine:
                  eos_id: Optional[int] = None, telemetry=None):
         """``telemetry`` (a ``repro.obs`` sink; default ``NullSink`` = off)
         receives one ``ServeSample`` per ``step()``: fenced chunk wall
-        time, inter-token latency, TTFT for requests admitted that step,
-        block-pool occupancy, queue depth, admission/eviction counts.
-        With the default sink the engine adds no fences or host reads."""
+        time, inter-token latency, TTFT for requests admitted that step
+        (submission to their first tokens read back on the host, which
+        happens in the step that admits them), block-pool occupancy,
+        queue depth, admission/eviction counts. A sink adds one fence per
+        step, on the chunk; with the default sink the engine adds no
+        fences or host reads."""
         for i in range(cfg.n_layers):
             if cfg.layer_is_cross_attn(i):
                 raise NotImplementedError(
@@ -188,14 +199,14 @@ class ContinuousEngine:
             "temps": jnp.zeros((n,), jnp.float32),
         }
 
-        self._prefill = jax.jit(functools.partial(prefill, cfg))
+        self._prefill = jax.jit(prefill, static_argnums=0)
         # prefill scratch caches keyed (batch, prompt bucket): the scratch
         # only has to hold the prompt (write_prefill reads nothing past
         # it), so admission never copies max_len-wide buffers
         self._mono_scratch: Dict[tuple, Dict] = {}
 
-        def chunk_fn(params, pools, tables, st, stop_early, *,
-                     max_steps: int):
+        def decode_chunk(params, pools, tables, st, stop_early, *,
+                         max_steps: int):
             entry_active = st["active"]
             keys, temps = st["keys"], st["temps"]
             rows = jnp.arange(entry_active.shape[0])
@@ -245,7 +256,7 @@ class ContinuousEngine:
                            "remaining": rem, "active": act, "out_buf": ob,
                            "out_pos": op}, t
 
-        self._chunk = jax.jit(chunk_fn, static_argnames=("max_steps",))
+        self._chunk = jax.jit(decode_chunk, static_argnames=("max_steps",))
 
         def admit_state(st, lg, key, slot, seed, n_prompt, n_new, temp):
             """Fold a freshly prefilled request into the batch state: sample
@@ -312,109 +323,120 @@ class ContinuousEngine:
         prefill into a bucketed scratch cache; each request's prefill KV is
         then scattered into its reserved blocks and its first token folded
         into the batch state — it rides ``out_buf[slot, 0]`` and is
-        collected with the next chunk, so admission never syncs the host
-        (a telemetry sink adds one fence per prefill group, to stamp
-        first-token readiness for TTFT). Returns the admitted requests."""
-        admitted: List[Request] = []
-        while True:
-            req = self.scheduler.next_admit()
-            if req is None or not self.cache.can_admit(req.total_budget):
-                break
-            r = self.scheduler.admit()
-            self.cache.reserve(r.slot, r.total_budget)
-            admitted.append(r)
-        by_len: Dict[int, List[Request]] = {}
-        for r in admitted:
-            by_len.setdefault(len(r.prompt), []).append(r)
-        for n_prompt, group in by_len.items():
-            k = len(group)
-            bucket = min(self.max_len,
-                         1 << max(3, (n_prompt - 1).bit_length()))
-            if (k, bucket) not in self._mono_scratch:
-                self._mono_scratch[(k, bucket)] = init_cache(
-                    self.cfg, k, bucket, self.cache_dtype)
-            prompts = jnp.asarray(np.stack([r.prompt for r in group]))
-            logits, mono = self._prefill(self.params, prompts,
-                                         self._mono_scratch[(k, bucket)],
-                                         None)
-            for i, r in enumerate(group):
-                self.cache.write_prefill(r.slot, mono, n_prompt, row=i)
-                self._st = self._admit_state(
-                    self._st, logits[i, -1], self._key, r.slot, r.seed,
-                    n_prompt, r.n_new, jnp.float32(r.temperature))
-            if self.telemetry.enabled:
-                # first token sampled for every request of this group —
-                # fence once, stamp TTFT readiness for the whole group.
-                jax.block_until_ready(self._st["out_buf"])
-                now = time.perf_counter()
-                for r in group:
-                    r.t_first = now
+        collected with the next chunk, so admission never syncs the host.
+        Each request gets ``t_admitted`` once its scatter and first-token
+        sampling are dispatched. Returns the admitted requests."""
+        with span("serve.admit"):
+            admitted: List[Request] = []
+            while True:
+                req = self.scheduler.next_admit()
+                if req is None or not self.cache.can_admit(req.total_budget):
+                    break
+                r = self.scheduler.admit()
+                self.cache.reserve(r.slot, r.total_budget)
+                admitted.append(r)
+            by_len: Dict[int, List[Request]] = {}
+            for r in admitted:
+                by_len.setdefault(len(r.prompt), []).append(r)
+            for n_prompt, group in by_len.items():
+                k = len(group)
+                with span("serve.prefill", k=k, n_prompt=n_prompt):
+                    bucket = min(self.max_len,
+                                 1 << max(3, (n_prompt - 1).bit_length()))
+                    if (k, bucket) not in self._mono_scratch:
+                        self._mono_scratch[(k, bucket)] = init_cache(
+                            self.cfg, k, bucket, self.cache_dtype)
+                    prompts = jnp.asarray(np.stack([r.prompt for r in group]))
+                    logits, mono = self._prefill(
+                        self.cfg, self.params, prompts,
+                        self._mono_scratch[(k, bucket)], None)
+                for i, r in enumerate(group):
+                    with span("serve.scatter", rid=r.rid, slot=r.slot):
+                        self.cache.write_prefill(r.slot, mono, n_prompt,
+                                                 row=i)
+                        self._st = self._admit_state(
+                            self._st, logits[i, -1], self._key, r.slot,
+                            r.seed, n_prompt, r.n_new,
+                            jnp.float32(r.temperature))
+                    r.t_admitted = time.perf_counter()
         return admitted
 
     def _collect(self) -> List[Request]:
+        """Read the chunk's tokens back (this blocks until the chunk
+        ends), hand them to their requests, stamp ``t_first`` on the
+        requests that got their first tokens, and recycle finished
+        slots."""
         st = self._st
-        out_pos = np.asarray(st["out_pos"])
-        out_buf = np.asarray(st["out_buf"])
-        active = np.asarray(st["active"])
-        finished: List[Request] = []
-        for slot, req in list(self.scheduler.running.items()):
-            k = int(out_pos[slot])
-            if k:
-                req.tokens.extend(int(t) for t in out_buf[slot, :k])
-                self.tokens_generated += k
-            if not active[slot]:         # budget spent or stop token emitted
-                self.cache.release(slot)
-                finished.append(self.scheduler.evict(slot))
-        st["out_pos"] = jnp.zeros_like(st["out_pos"])
+        with span("serve.readback"):
+            out_pos = np.asarray(st["out_pos"])
+            out_buf = np.asarray(st["out_buf"])
+            active = np.asarray(st["active"])
+        with span("serve.collect"):
+            now = time.perf_counter()
+            finished: List[Request] = []
+            for slot, req in list(self.scheduler.running.items()):
+                k = int(out_pos[slot])
+                if k:
+                    if req.t_first is None:
+                        req.t_first = now
+                    req.tokens.extend(int(t) for t in out_buf[slot, :k])
+                    self.tokens_generated += k
+                if not active[slot]:     # budget spent or stop token emitted
+                    self.cache.release(slot)
+                    finished.append(self.scheduler.evict(slot))
+            st["out_pos"] = jnp.zeros_like(st["out_pos"])
         return finished
 
     def step(self) -> List[Request]:
         """One scheduling round: admit waiting requests into free slots,
         run one jitted decode chunk, collect tokens and recycle finished
         slots. Returns the requests that finished this round."""
-        tele = self.telemetry
-        obs_on = tele.enabled
-        admitted = self._admit_all()
-        if not self.scheduler.running:
-            return []
-        stop_early = jnp.asarray(bool(self.scheduler.queue))
-        # attend only over block-table columns actually backed by reserved
-        # blocks (bucketed to a power of two to bound retraces) — the
-        # monolithic engine must attend over the whole max_len budget
-        tables = self.cache.tables
-        full = tables.get("full")
-        w = self.cache.used_width()
-        if full is not None and w is not None and w < full.shape[1]:
-            tables = {**tables, "full": full[:, :w]}
-        t0 = time.perf_counter() if obs_on else 0.0
-        pools, st, t = self._chunk(self.params, self.cache.pools,
-                                   tables, self._st, stop_early,
-                                   max_steps=self.chunk)
-        self.cache.pools = pools
-        self._st = st
-        if obs_on:
-            jax.block_until_ready(st["out_pos"])
-            chunk_s = time.perf_counter() - t0
-            steps = int(t)               # host read: telemetry only
-        tokens_before = self.tokens_generated
-        finished = self._collect()
-        if obs_on:
-            now = time.perf_counter()
-            tokens = self.tokens_generated - tokens_before
-            free = self.cache.free_blocks()
-            total = self.cache._group_phys.get("full", 0)
-            tele.emit(ServeSample(
-                chunk_s=chunk_s, steps=steps, tokens=tokens,
-                itl_s=chunk_s / max(steps, 1),
-                n_running=self.n_running,
-                queue_depth=len(self.scheduler.queue),
-                admitted=len(admitted), finished=len(finished),
-                blocks_free=free, blocks_total=total,
-                occupancy=(1.0 - free / total) if total else 0.0,
-                ttft_s=[r.t_first - r.t_submit for r in admitted
-                        if r.t_first is not None],
-                e2e_s=[now - r.t_submit for r in finished]))
-        return finished
+        with span("serve.step"):
+            tele = self.telemetry
+            obs_on = tele.enabled
+            admitted = self._admit_all()
+            if not self.scheduler.running:
+                return []
+            with span("serve.tables"):
+                # attend only over block-table columns actually backed by
+                # reserved blocks (bucketed to a multiple of four to bound
+                # retraces) — the monolithic engine must attend over the
+                # whole max_len budget
+                tables = self.cache.tables
+                full = tables.get("full")
+                w = self.cache.used_width()
+                if full is not None and w is not None and w < full.shape[1]:
+                    tables = {**tables, "full": full[:, :w]}
+            with span("serve.dispatch"):
+                stop_early = jnp.asarray(bool(self.scheduler.queue))
+                t0 = time.perf_counter() if obs_on else 0.0
+                pools, st, t = self._chunk(self.params, self.cache.pools,
+                                           tables, self._st, stop_early,
+                                           max_steps=self.chunk)
+                self.cache.pools = pools
+                self._st = st
+            if obs_on:
+                jax.block_until_ready(st["out_pos"])
+                chunk_s = time.perf_counter() - t0
+                steps = int(t)               # host read: telemetry only
+            tokens_before = self.tokens_generated
+            finished = self._collect()
+            if obs_on:
+                now = time.perf_counter()
+                tokens = self.tokens_generated - tokens_before
+                free = self.cache.free_blocks()
+                total = self.cache._group_phys.get("full", 0)
+                tele.emit(ServeSample(
+                    chunk_s=chunk_s, steps=steps, tokens=tokens,
+                    itl_s=chunk_s / max(steps, 1),
+                    n_running=self.n_running,
+                    queue_depth=len(self.scheduler.queue),
+                    admitted=len(admitted), finished=len(finished),
+                    blocks_free=free, blocks_total=total,
+                    occupancy=(1.0 - free / total) if total else 0.0,
+                    ttft_s=[r.t_first - r.t_submit for r in admitted],
+                    e2e_s=[now - r.t_submit for r in finished]))
+            return finished
 
     def run(self) -> Dict[int, np.ndarray]:
         """Drain queue + running batch; returns {rid: generated tokens}."""

@@ -26,10 +26,15 @@ class Request:
     seed: int = 0
     tokens: List[int] = field(default_factory=list)   # generated so far
     slot: Optional[int] = None
-    # host wall-clock marks (perf_counter domain) for latency telemetry:
-    # submission, and first-token readiness (set by the engine at the end
-    # of the request's prefill when a telemetry sink is attached).
+    # host clock stamps (perf_counter), always kept, one reading each:
+    # submission; a slot bound (``Scheduler.admit``); the request's
+    # scatter and first-token sampling dispatched (engine admission); its
+    # first tokens read back on the host (the end of the admitting step's
+    # chunk). TTFT is ``t_first - t_submit``: queue wait to the slot, the
+    # admission dispatch, then the prefill and first chunk on the device.
     t_submit: float = field(default_factory=perf_counter)
+    t_admit: Optional[float] = None
+    t_admitted: Optional[float] = None
     t_first: Optional[float] = None
 
     @property
@@ -78,6 +83,7 @@ class Scheduler:
         """Bind the head-of-queue request to a free slot."""
         req = self.queue.popleft()
         req.slot = self._free_slots.pop()
+        req.t_admit = perf_counter()
         self.running[req.slot] = req
         return req
 

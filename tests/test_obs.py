@@ -291,6 +291,9 @@ def test_continuous_engine_emits_serve_samples_and_stays_bitwise():
     assert total_tokens == eng.tokens_generated
     ttft = [t for s in samples for t in s.ttft_s]
     assert len(ttft) == 3 and all(t > 0 for t in ttft)
+    # TTFT ends when the first tokens reach the host, in the admitting step
+    assert sorted(ttft) == sorted(r.t_first - r.t_submit for r in
+                                  eng.scheduler.finished.values())
     e2e = [t for s in samples for t in s.e2e_s]
     assert len(e2e) == 3 and all(t > 0 for t in e2e)
     for s in samples:
@@ -302,6 +305,30 @@ def test_continuous_engine_emits_serve_samples_and_stays_bitwise():
     # telemetry must not perturb decoding
     _, _, eng2, _ = _serve_setup()
     np.testing.assert_array_equal(out, eng2.generate(prompts, n_new=12))
+
+
+def test_span_records_name_and_ids_only_under_the_profiler(tmp_path):
+    import glob
+    import warnings
+    from jax.profiler import ProfileData, TraceAnnotation
+    from repro.obs import span
+    assert isinstance(span("serve.step"), TraceAnnotation)
+    with span("serve.outside", rid=7):           # usable with no session
+        pass
+    with jax.profiler.trace(str(tmp_path)):
+        with span("serve.step"):
+            with span("serve.scatter", rid=3, slot=1):
+                pass
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    with warnings.catch_warnings():   # jaxlib's stats type lacks __module__
+        warnings.simplefilter("ignore", DeprecationWarning)
+        got = {ev.name: dict(ev.stats)
+               for plane in ProfileData.from_file(path).planes
+               if plane.name.startswith("/host:")
+               for line in plane.lines for ev in line.events
+               if ev.name.startswith("serve.")}
+    assert got == {"serve.step": {}, "serve.scatter": {"rid": 3, "slot": 1}}
 
 
 def test_hot_swap_bridge_emits_hot_swap_event():

@@ -224,3 +224,108 @@ def test_swap_params_identity_under_same_params():
     b = swapped.run()[rid]
     np.testing.assert_array_equal(a, b)
     assert swapped.n_swaps == 1
+
+
+# ---------------------------------------------------------------------------
+# Spans and per-request stamps
+# ---------------------------------------------------------------------------
+
+def _queued_run(eng, cfg):
+    """Five requests of three prompt lengths through two slots, so later
+    requests queue and some steps admit a prefill group of two."""
+    prompts = np.asarray(lm_batch(12, 5, 12, cfg.vocab_size)["tokens"])
+    lens, n_news = [8, 8, 12, 5, 12], [6, 9, 3, 7, 5]
+    rids = [eng.submit(prompts[i][:lens[i]], n_news[i], seed=i)
+            for i in range(5)]
+    done = eng.run()
+    return rids, [done[r] for r in rids]
+
+
+def _serve_events(trace_dir):
+    """``(start, end, name, args)`` of every ``serve.*`` host event of the
+    one trace written under ``trace_dir``."""
+    import glob
+    import warnings
+    from jax.profiler import ProfileData
+    path, = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    out = []
+    with warnings.catch_warnings():   # jaxlib's stats type lacks __module__
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for ev in line.events:
+                        if ev.name.startswith("serve."):
+                            out.append((ev.start_ns,
+                                        ev.start_ns + ev.duration_ns,
+                                        ev.name, dict(ev.stats)))
+    return path, sorted(out, key=lambda e: (e[0], -e[1]))
+
+
+def test_profiled_step_spans_nest_and_name_the_requests(tmp_path):
+    """Under the profiler each ``serve.step`` holds one ``serve.readback``,
+    prefill groups and scatters sit inside ``serve.admit``, the scatters'
+    ``rid`` args are the admitted requests, and the greedy tokens are those
+    of a run with the profiler off. The benchmark's trace reduction keeps
+    only its own ``bench.`` spans, so its idle labels do not change."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from bench import trace as bench_trace
+
+    cfg, params = _setup("yi-6b", seed=12)
+
+    def engine():
+        return ContinuousEngine(cfg, params, n_slots=2, max_len=64,
+                                block_size=8, cache_dtype=jnp.float32,
+                                chunk=4)
+
+    _, plain = _queued_run(engine(), cfg)
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("bench.window"):
+            rids, traced = _queued_run(engine(), cfg)
+    for a, b in zip(plain, traced):
+        np.testing.assert_array_equal(a, b)
+
+    path, ev = _serve_events(tmp_path)
+
+    def named(n):
+        return [e for e in ev if e[2] == n]
+
+    def inside(e, outer):
+        return outer[0] <= e[0] and e[1] <= outer[1]
+
+    steps, admits = named("serve.step"), named("serve.admit")
+    assert steps and len(admits) == len(steps)
+    for name in ("serve.readback", "serve.tables", "serve.dispatch",
+                 "serve.collect"):
+        for s in steps:
+            assert sum(inside(e, s) for e in named(name)) == 1, name
+    prefills, scatters = named("serve.prefill"), named("serve.scatter")
+    for e in prefills + scatters:
+        assert any(inside(e, a) for a in admits)
+    assert sorted(e[3]["rid"] for e in scatters) == sorted(rids)
+    assert sum(e[3]["k"] for e in prefills) == len(rids)
+    assert any(e[3]["k"] == 2 for e in prefills)
+    assert {e[3]["n_prompt"] for e in prefills} == {5, 8, 12}
+
+    spans = bench_trace.load(path).spans
+    assert [n for _, _, n in spans] == ["bench.window"]
+
+
+def test_request_stamps_are_ordered():
+    """Every finished request was stamped at each boundary, in order:
+    submitted, bound to a slot, its admission dispatched, its first
+    tokens read back."""
+    cfg, params = _setup("yi-6b", seed=13)
+    eng = ContinuousEngine(cfg, params, n_slots=2, max_len=64, block_size=8,
+                           cache_dtype=jnp.float32, chunk=4)
+    rids, _ = _queued_run(eng, cfg)
+    reqs = [eng.scheduler.finished[r] for r in rids]
+    for r in reqs:
+        assert r.t_submit <= r.t_admit <= r.t_admitted <= r.t_first
+    # the two slots make the last requests wait for a free one
+    assert max(r.t_admit - r.t_submit for r in reqs) \
+        > min(r.t_admit - r.t_submit for r in reqs)
