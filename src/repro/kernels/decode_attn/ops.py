@@ -30,11 +30,13 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                           block_table: jax.Array, index: jax.Array, *,
+                           block_table: jax.Array, index: jax.Array,
+                           active: Optional[jax.Array] = None, *,
                            ring: Optional[int] = None,
                            window: Optional[int] = None) -> jax.Array:
     """Model-layout entry for the paged cache: q (b, 1, h, hd), pools
-    (n_pool, block_size, kv, hd), block_table (b, n_blk), index (b,).
+    (n_pool, block_size, kv, hd), block_table (b, n_blk), index (b,),
+    active (b,) bool or None; an inactive row's output is zeros.
 
     Off-TPU this routes to the pure-jnp reference rather than interpret-mode
     Pallas: the serving engine traces this inside a jitted ``lax.while_loop``
@@ -46,10 +48,11 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     qg = q.reshape(b, kv, g, hd)
     if _interpret():
         out = paged_decode_attn_ref(qg, k_pool, v_pool, block_table, index,
-                                    ring=ring, window=window)
+                                    active, ring=ring, window=window)
     else:
         out = paged_decode_attn(qg, k_pool, v_pool, block_table, index,
-                                ring=ring, window=window, interpret=False)
+                                active, ring=ring, window=window,
+                                interpret=False)
     return out.reshape(b, 1, h, hd)
 
 

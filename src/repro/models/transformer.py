@@ -522,7 +522,8 @@ def decode_step_paged(cfg: ModelConfig, params: Dict, tokens: jax.Array,
     ``{"attn": PagedKV}`` / ``{"ssm": SSMState}`` with leading pool / slot
     dims. ``active`` (n,) bool, when given, redirects inactive rows' KV
     writes to the trash block (last pool row) and freezes their SSM state,
-    so finished requests can ride in the batch without corrupting anything.
+    so finished requests can ride in the batch without corrupting anything;
+    the paged kernel walks none of their pages and gives them zeros.
     """
     layout = cache_layout(cfg, max_len, block_size)
     compute_dtype = jnp.dtype(cfg.compute_dtype)
@@ -561,7 +562,8 @@ def decode_step_paged(cfg: ModelConfig, params: Dict, tokens: jax.Array,
             k_pool = kv.k.at[pb, off].set(k[:, 0].astype(kv.k.dtype))
             v_pool = kv.v.at[pb, off].set(v[:, 0].astype(kv.v.dtype))
             att = paged_decode_attention(q, k_pool, v_pool, table, index,
-                                         ring=ring, window=al["window"])
+                                         active, ring=ring,
+                                         window=al["window"])
             x = x + jnp.einsum("bshk,hkd->bsd", att, wo)
             out_entry["attn"] = PagedKV(k_pool, v_pool)
         if cfg.layer_is_ssm(i):

@@ -397,14 +397,18 @@ class ContinuousEngine:
             admitted = self._admit_all()
             if not self.scheduler.running:
                 return []
-            with span("serve.tables"):
-                # attend only over block-table columns actually backed by
-                # reserved blocks (bucketed to a multiple of four to bound
-                # retraces) — the monolithic engine must attend over the
-                # whole max_len budget
+            # attend only over block-table columns actually backed by
+            # reserved blocks (bucketed to a multiple of four to bound
+            # retraces) — the monolithic engine must attend over the whole
+            # max_len budget. The span's ``width`` (full-attention table
+            # blocks the chunk sees, 0 without that group) and ``live``
+            # (blocks the running rows reserved) say how much of a walk
+            # over the whole width the paged kernel skips.
+            w = self.cache.used_width()
+            with span("serve.tables", width=w or 0,
+                      live=self.cache.reserved_blocks()):
                 tables = self.cache.tables
                 full = tables.get("full")
-                w = self.cache.used_width()
                 if full is not None and w is not None and w < full.shape[1]:
                     tables = {**tables, "full": full[:, :w]}
             with span("serve.dispatch"):

@@ -126,6 +126,10 @@ class PagedCache:
         n_blk = self.layout["groups"]["full"]["n_blk"]
         return min(n_blk, 4 * (-(-used // 4)))
 
+    def reserved_blocks(self) -> int:
+        """Full-group blocks reserved by the running requests, summed."""
+        return sum(len(b) for b in self._owned.values())
+
     def reserve(self, slot: int, n_tokens: int) -> None:
         """Reserve the request's full token budget up front so the decode
         loop never allocates."""

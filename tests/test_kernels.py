@@ -210,23 +210,57 @@ def _paged_setup(b, kv, g, hd, bs, nblk, seed):
     return q, kp, vp, tab
 
 
-@pytest.mark.parametrize("b,kv,g,hd,bs,nblk", [
-    (2, 1, 4, 32, 16, 4),
-    (3, 2, 2, 16, 8, 6),
+# Layouts a case is checked under: linear, a ring of the table's capacity,
+# and a sliding window of a third of it.
+LIN_RING = ((False, None), (True, None), (True, 3))
+RING = ((True, None), (True, 3))
+
+
+@pytest.mark.parametrize("b,kv,g,hd,bs,nblk,index,active,layouts", [
+    pytest.param(2, 1, 4, 32, 16, 4, None, None, LIN_RING,
+                 id="2-1-4-32-16-4"),
+    pytest.param(3, 2, 2, 16, 8, 6, None, None, LIN_RING,
+                 id="3-2-2-16-8-6"),
+    # Yi's grouping and head width (the DMA form), 40 pages of 16 walked 16
+    # at a time: one token; a row ending mid-way through its second compute
+    # block; the table's full width, whose last block is half used; an
+    # inactive row
+    pytest.param(4, 4, 8, 128, 16, 40, (0, 16 * 24 + 5, 639, 200),
+                 (True, True, True, False), LIN_RING, id="grouped-mixed"),
+    # the same walks with 32-lane heads (the BlockSpec form): 10 pages of 64
+    # tokens, 4 a step, so the width is no multiple of the step
+    pytest.param(4, 2, 2, 32, 64, 10, (0, 64 * 6 + 5, 639, 200),
+                 (True, True, True, False), LIN_RING, id="narrow-mixed"),
+    # rings past their wrap, every page live again; idle rows first and
+    # between, which the DMA form's prefetch skips
+    pytest.param(4, 4, 8, 128, 16, 40, (640 + 17, 2 * 640 + 400, 641, 700),
+                 (False, True, False, True), RING, id="grouped-wrapped"),
+    pytest.param(4, 2, 2, 32, 64, 10, (640 + 17, 2 * 640 + 400, 641, 700),
+                 (False, True, False, True), RING, id="narrow-wrapped"),
 ])
-def test_paged_decode_attn_kernel_vs_ref(b, kv, g, hd, bs, nblk):
+def test_paged_decode_attn_kernel_vs_ref(b, kv, g, hd, bs, nblk, index,
+                                         active, layouts):
+    """Kernel against the gathered reference row for row; an inactive row
+    reads nothing and gives zeros in both."""
     from repro.kernels.decode_attn import (paged_decode_attn,
                                            paged_decode_attn_ref)
     q, kp, vp, tab = _paged_setup(b, kv, g, hd, bs, nblk, seed=b)
     S = bs * nblk
-    idx = jnp.asarray([(7 * i + 3) % S for i in range(b)], jnp.int32)
-    for ring, window in [(None, None), (S, None), (S, S // 3)]:
-        out = paged_decode_attn(q, kp, vp, tab, idx, ring=ring,
+    idx = jnp.asarray([(7 * i + 3) % S for i in range(b)] if index is None
+                      else index, jnp.int32)
+    act = None if active is None else jnp.asarray(active)
+    for is_ring, window_div in layouts:
+        ring = S if is_ring else None
+        window = None if window_div is None else S // window_div
+        out = paged_decode_attn(q, kp, vp, tab, idx, act, ring=ring,
                                 window=window, interpret=True)
-        ref = paged_decode_attn_ref(q, kp, vp, tab, idx, ring=ring,
+        ref = paged_decode_attn_ref(q, kp, vp, tab, idx, act, ring=ring,
                                     window=window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=3e-5, atol=3e-5)
+        if act is not None:
+            assert not np.asarray(out)[~np.asarray(act)].any()
+            assert np.asarray(out)[np.asarray(act)].any()
 
 
 def test_paged_linear_matches_dense_oracle():

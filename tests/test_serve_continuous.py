@@ -315,6 +315,26 @@ def test_profiled_step_spans_nest_and_name_the_requests(tmp_path):
     assert [n for _, _, n in spans] == ["bench.window"]
 
 
+def test_tables_span_carries_width_and_live_blocks(tmp_path):
+    """Each ``serve.tables`` span carries the full-attention table width
+    the chunk sees and the blocks the running rows reserved, which never
+    exceed the slots times that width; the queued run leaves part of the
+    width unreserved, the part the paged kernel does not walk."""
+    cfg, params = _setup("yi-6b", seed=14)
+    eng = ContinuousEngine(cfg, params, n_slots=2, max_len=64, block_size=8,
+                           cache_dtype=jnp.float32, chunk=4)
+    with jax.profiler.trace(str(tmp_path)):
+        _queued_run(eng, cfg)
+    _, ev = _serve_events(tmp_path)
+    tables = [e[3] for e in ev if e[2] == "serve.tables"]
+    assert tables
+    n_blk = eng.cache.layout["groups"]["full"]["n_blk"]
+    for args in tables:
+        assert 0 < args["width"] <= n_blk
+        assert 0 < args["live"] <= 2 * args["width"]
+    assert any(a["live"] < 2 * a["width"] for a in tables)
+
+
 def test_request_stamps_are_ordered():
     """Every finished request was stamped at each boundary, in order:
     submitted, bound to a slot, its admission dispatched, its first

@@ -76,25 +76,35 @@ def test_wagg_fused_compiles_for_v5e(one_chip, payload_dtype, masked):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("ring,window", [
-    (None, None),                   # full-attention layers: linear table
-    (160, None),                    # ring of the table's capacity
-    (160, 100),                     # sliding-window layers
-])
-def test_paged_decode_attn_compiles_for_v5e(one_chip, ring, window):
-    """stablelm-1.6b decode: kv=32 heads of 64, bf16 pools of 16-token
-    blocks, 8 requests of up to 160 tokens; the ring cases are the layout
-    windowed archs serve through."""
-    b, kv, g, hd, bs, n_blk = 8, 32, 1, 64, 16, 10
+# (b, kv, g, hd, bs, n_blk, ring, window)
+PAGED_CASES = [
+    # stablelm-1.6b decode: kv=32 heads of 64, 8 requests of up to 160
+    # tokens; the ring cases are the layout windowed archs serve through
+    pytest.param(8, 32, 1, 64, 16, 10, None, None, id="None-None"),
+    pytest.param(8, 32, 1, 64, 16, 10, 160, None, id="160-None"),
+    pytest.param(8, 32, 1, 64, 16, 10, 160, 100, id="160-100"),
+    # Yi-6B serving: 16 slots, 4 kv heads of 128 under 8 query heads each,
+    # a 4096-token table (256 blocks)
+    pytest.param(16, 4, 8, 128, 16, 256, None, None, id="yi-None-None"),
+    pytest.param(16, 4, 8, 128, 16, 256, 4096, 1024, id="yi-4096-1024"),
+]
+
+
+@pytest.mark.parametrize("b,kv,g,hd,bs,n_blk,ring,window", PAGED_CASES)
+def test_paged_decode_attn_compiles_for_v5e(one_chip, b, kv, g, hd, bs, n_blk,
+                                            ring, window):
+    """Paged decode with bf16 pools of 16-token blocks, the per-row
+    ``active`` mask given, at stablelm's and Yi's widths."""
     n_pool = b * n_blk + 1
     args = [_shape(one_chip, (b, kv, g, hd), jnp.bfloat16),
             _shape(one_chip, (n_pool, bs, kv, hd), jnp.bfloat16),
             _shape(one_chip, (n_pool, bs, kv, hd), jnp.bfloat16),
             _shape(one_chip, (b, n_blk), jnp.int32),
-            _shape(one_chip, (b,), jnp.int32)]
+            _shape(one_chip, (b,), jnp.int32),
+            _shape(one_chip, (b,), jnp.bool_)]
 
-    def attn(q, k, v, table, index):
-        return paged_decode_attn(q, k, v, table, index, ring=ring,
+    def attn(q, k, v, table, index, active):
+        return paged_decode_attn(q, k, v, table, index, active, ring=ring,
                                  window=window, interpret=False)
 
     compiled = jax.jit(attn).lower(*args).compile()
