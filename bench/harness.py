@@ -254,9 +254,10 @@ class Window:
 
 @dataclasses.dataclass
 class LayerInputs:
-    """What a per-layer reader may read: the reduced trace of the window,
-    the chip's peaks, the configuration, and the driver's own counts of
-    the work it issued in the window."""
+    """What a per-layer reader may read: the reduced trace of the window
+    (``bench/trace.py``; the program's spans and their args are in
+    ``trace.program_spans``), the chip's peaks, the configuration, and the
+    cell's own counts of the work it issued in the window."""
     trace: object
     peaks: dict
     chips: int
@@ -312,21 +313,14 @@ def emit(result: dict, chk: dict) -> None:
 
 
 def program_config(cfg: dict):
-    """The program's ``ModelConfig`` for a dense decoder configuration
-    file."""
-    from repro.configs.base import ModelConfig
-    prec = cfg["precision"]
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg["head_dim"], d_ff=cfg["intermediate_size"],
-        vocab_size=cfg["vocab_size"], rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        param_dtype=prec["params"], compute_dtype=prec["compute"],
-        source=cfg["source"])
+    """The program's ``ModelConfig`` for a configuration file: read by the
+    ``from_config`` of the module the file names under ``reader``, else by
+    ``bench/published.py``. A file that names no reader keeps this one,
+    whatever modules are added later."""
+    if "reader" in cfg:
+        return importlib.import_module(cfg["reader"]).from_config(cfg)
+    from bench.published import from_config
+    return from_config(cfg)
 
 
 def same_layout(a: dict, b: dict, path=()) -> Optional[str]:

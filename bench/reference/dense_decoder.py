@@ -13,6 +13,9 @@ It computes in blocks so that it fits beside nothing else on one chip:
 attention by blocks of 512 queries, the loss by blocks of 512 tokens, and
 each layer under ``jax.checkpoint`` when differentiated.
 
+``counts`` gives what each layer holds, for the FLOP and byte counts of
+``bench/flops.py``.
+
 ``precision="fp8"`` is the control: every matmul operand is rounded to the
 3 mantissa bits of float8 e4m3 (exponent range unlimited, as a scaled fp8
 path has it) before an f32-accumulating product.
@@ -31,6 +34,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import flops
 
 HIGHEST = jax.lax.Precision.HIGHEST
 BLOCK = 512
@@ -77,6 +82,20 @@ def layout(cfg: dict, dtype=jnp.float32) -> Tuple[Dict, Dict]:
         return {n: split(x, k) if isinstance(x, dict) else x[k]
                 for n, x in t.items()}
     return split(tree, 0), split(tree, 1)
+
+
+def counts(cfg: dict) -> flops.Counts:
+    """Per layer: q, k, v, o and the gated MLP's three matrices, which each
+    token multiplies; two norm scales; attention over every cached token.
+    Outside the layers: the untied output head, the final norm, and one
+    embedding row per token."""
+    m = dims(cfg)
+    d, h, kv, hd, ff = m["d"], m["h"], m["kv"], m["hd"], m["ff"]
+    layer = flops.Layer(
+        matmul=d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * ff,
+        other=2 * d, attention=flops.Attention(h, kv, hd))
+    return flops.Counts(layers=(layer,) * m["n"], head=d * m["v"], other=d,
+                        embed_row=d)
 
 
 # -- arithmetic ------------------------------------------------------------------

@@ -8,7 +8,11 @@ it, all in nanoseconds on the trace's own clock:
   ``(start, end, name)``;
 * the host spans the harness opened with ``jax.profiler.TraceAnnotation``
   (names that start with ``bench.``);
-* the measured window: the ``bench.window`` span.
+* the measured window: the ``bench.window`` span;
+* the host spans the program opened through ``repro.obs.span`` (names
+  that start with ``serve.`` or ``train.``), with their args, for the
+  per-layer readers (``Trace.program_spans``). Idle labels, the breakdown
+  and the outline use the harness's spans alone.
 
 The functions below reduce those intervals: busy time is the union of a
 device's operation intervals inside the window, a collective's exposed time
@@ -20,11 +24,14 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
+import warnings
+from typing import (Callable, Dict, Iterable, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 Interval = Tuple[float, float, str]
 
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIXES = ("serve.", "train.")
 WINDOW_SPAN = "bench.window"
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
@@ -40,11 +47,20 @@ class Device:
     modules: List[Interval]
 
 
+class Span(NamedTuple):
+    """A program span: start and end on the trace's clock, name, args."""
+    start: float
+    end: float
+    name: str
+    args: Dict
+
+
 @dataclasses.dataclass
 class Trace:
     devices: List[Device]
     spans: List[Interval]
     window: Tuple[float, float]
+    program_spans: List[Span] = dataclasses.field(default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -78,26 +94,36 @@ def load(path: str) -> Trace:
     from jax.profiler import ProfileData
     devices: List[Device] = []
     spans: List[Interval] = []
-    for plane in ProfileData.from_file(path).planes:
-        if DEVICE_PLANE.match(plane.name):
-            lines = {line.name: line for line in plane.lines}
-            devices.append(Device(
-                plane.name,
-                ops=_intervals(lines.get(OPS_LINE), op_name),
-                modules=_intervals(lines.get(MODULES_LINE), module_name)))
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name.startswith(SPAN_PREFIX):
-                        spans.append((ev.start_ns, ev.start_ns
-                                      + ev.duration_ns, ev.name))
+    program: List[Span] = []
+    with warnings.catch_warnings():   # jaxlib's stats type lacks __module__
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            if DEVICE_PLANE.match(plane.name):
+                lines = {line.name: line for line in plane.lines}
+                devices.append(Device(
+                    plane.name,
+                    ops=_intervals(lines.get(OPS_LINE), op_name),
+                    modules=_intervals(lines.get(MODULES_LINE),
+                                       module_name)))
+            elif plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for ev in line.events:
+                        if ev.name.startswith(SPAN_PREFIX):
+                            spans.append((ev.start_ns, ev.start_ns
+                                          + ev.duration_ns, ev.name))
+                        elif ev.name.startswith(PROGRAM_PREFIXES):
+                            program.append(Span(
+                                ev.start_ns, ev.start_ns + ev.duration_ns,
+                                ev.name, dict(ev.stats)))
     devices.sort(key=lambda d: int(DEVICE_PLANE.match(d.name).group(1)))
-    return from_parts(devices, spans)
+    return from_parts(devices, spans, program)
 
 
-def from_parts(devices: List[Device], spans: List[Interval]) -> Trace:
+def from_parts(devices: List[Device], spans: List[Interval],
+               program_spans: Iterable[Span] = ()) -> Trace:
     """A ``Trace`` from intervals; the window is the ``bench.window`` span,
-    or the whole extent of the device operations where there is none."""
+    or the whole extent of the device operations where there is none.
+    ``program_spans`` are kept as they are, ordered by start and end."""
     win = [s for s in spans if s[2] == WINDOW_SPAN]
     if win:
         window = (win[0][0], win[0][1])
@@ -105,7 +131,8 @@ def from_parts(devices: List[Device], spans: List[Interval]) -> Trace:
         every = [iv for d in devices for iv in d.ops + d.modules]
         window = ((min(iv[0] for iv in every), max(iv[1] for iv in every))
                   if every else (0.0, 0.0))
-    return Trace(devices, sorted(spans), window)
+    return Trace(devices, sorted(spans), window,
+                 sorted(program_spans, key=lambda s: (s.start, -s.end)))
 
 
 def _intervals(line, short) -> List[Interval]:

@@ -131,3 +131,104 @@ def test_prefill_reader_reads_only_when_its_programs_are_counted(
         modules, admitted, groups, expected):
     got = _prefill_reading(sorted(modules), admitted, groups)
     assert got == (pytest.approx(expected) if expected else None)
+
+
+# -- the program's spans ----------------------------------------------------
+
+PROGRAM = [tr.Span(30, 60, "serve.step", {}),
+           tr.Span(15, 45, "serve.tables", {"width": 256, "live": 12}),
+           tr.Span(32, 40, "serve.scatter", {"rid": 7, "slot": 3}),
+           tr.Span(70, 80, "train.round", {"round": 2})]
+
+
+def test_from_parts_keeps_program_spans_and_their_args():
+    t = tr.from_parts([dev([(0, 20, "a")])], WINDOW, PROGRAM)
+    assert [s.name for s in t.program_spans] == [
+        "serve.tables", "serve.step", "serve.scatter", "train.round"]
+    by = {s.name: s for s in t.program_spans}
+    assert by["serve.tables"].args == {"width": 256, "live": 12}
+    assert (by["serve.scatter"].start, by["serve.scatter"].end) == (32, 40)
+    assert by["serve.scatter"].args["rid"] == 7
+    assert tr.from_parts([dev([(0, 20, "a")])], WINDOW).program_spans == []
+
+
+@pytest.mark.parametrize("reduce", [
+    tr.idle_by_label, tr.outline, tr.top_ops, tr.mean_busy_s,
+    lambda t: t.spans, lambda t: t.window])
+def test_program_spans_leave_labels_and_outline_as_they_were(reduce):
+    d = dev([(0, 20, "a"), (50, 100, "b")])
+    spans = WINDOW + [(15, 45, "bench.chunk"), (25, 40, "bench.submit")]
+    assert reduce(tr.from_parts([d], spans, PROGRAM)) == \
+        reduce(tr.from_parts([d], spans))
+
+
+def test_load_keeps_the_program_spans_of_a_real_trace(tmp_path):
+    """Under the profiler on the CPU: spans opened through the program's
+    ``repro.obs.span`` reach ``program_spans`` with their args; the
+    harness's spans alone reach ``spans``."""
+    import glob
+    import jax
+    import jax.numpy as jnp
+    from repro.obs import span
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("bench.window"):
+            with span("serve.step"):
+                with span("serve.tables", width=16, live=5):
+                    jnp.ones(4).block_until_ready()
+            with span("train.round", round=1):
+                pass
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    t = tr.load(path)
+    assert [n for _, _, n in t.spans] == ["bench.window"]
+    by = {s.name: s for s in t.program_spans}
+    assert sorted(by) == ["serve.step", "serve.tables", "train.round"]
+    assert by["serve.tables"].args == {"width": 16, "live": 5}
+    assert by["train.round"].args == {"round": 1}
+    step, tables = by["serve.step"], by["serve.tables"]
+    assert step.start <= tables.start and tables.end <= step.end
+
+
+# The serving readers on one set of inputs, against what they read before
+# the trace kept program spans and the counts came from the reference.
+READ_BEFORE = {"mfu.serve": 7.5226465836385845,
+               "paged_decode_roofline": 24.727432319618366,
+               "prefill_ms.serve": 3.6666666666666665,
+               "device_idle.serve": 88.85}
+
+
+def _serve_inputs(program_spans=()):
+    import json
+    from bench import harness, peaks
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(repo, "bench", "configs", "yi-6b-l4.json")) as fh:
+        cfg = json.load(fh)
+    ops = [(0, 2e6, "fusion.1"),
+           (2e6, 2.5e6, "_paged_decode_kernel:custom-call.3"),
+           (3e6, 9e6, "convolution_bitcast_fusion.2"),
+           (9e6, 9.4e6, "_paged_decode_kernel:custom-call.4"),
+           (40e6, 41e6, "fusion.7"),
+           (60e6, 61.25e6, "_paged_decode_kernel:custom-call.3")]
+    modules = [(0, 4e6, "jit_prefill"), (4e6, 5e6, "jit_scatter"),
+               (5e6, 6e6, "jit_scatter"), (30e6, 34e6, "jit_prefill"),
+               (34e6, 35e6, "jit_scatter"), (40e6, 90e6, "jit_chunk_fn")]
+    t = tr.from_parts([dev(ops, modules)],
+                      [(0.0, 100e6, "bench.window"),
+                       (10e6, 30e6, "bench.chunk")], program_spans)
+    steps = [[1019, 1500, 40], [1020, 1501, 41, 7], [3000] * 16]
+    return harness.LayerInputs(
+        trace=t, peaks=peaks.peaks_for("TPU v5 lite"), chips=1, config=cfg,
+        counts={"decode_steps": steps, "admitted": 3, "prefill_groups": 2,
+                "weight_bytes": 2, "kv_bytes": 2})
+
+
+@pytest.mark.parametrize("metric", sorted(READ_BEFORE))
+@pytest.mark.parametrize("with_program_spans", [False, True])
+def test_serving_readers_read_what_they_read_before(metric,
+                                                    with_program_spans):
+    from bench import harness
+    spans = [tr.Span(1e6, 2e6, "serve.tables", {"width": 256, "live": 9})] \
+        if with_program_spans else ()
+    got = harness.metric_reader(metric).read(_serve_inputs(spans))
+    assert got == READ_BEFORE[metric]
